@@ -35,7 +35,7 @@ def run() -> None:
     m = jnp.zeros(n); v = jnp.zeros(n)
     f_ref = jax.jit(lambda *a: ref.ref_fused_adam(*a))
     us_ref = time_us(lambda: jax.block_until_ready(f_ref(p, gr, m, v, 1)))
-    out_k = ops.fused_adam(p, gr, m, v, 1)
+    out_k = ops.fused_adam(p, gr, m, v, 1, interpret=True)
     out_r = f_ref(p, gr, m, v, 1)
     err = float(jnp.abs(out_k[0] - out_r[0]).max())
     emit("kernel/fused-adam-1M", us_ref,
@@ -49,7 +49,7 @@ def run() -> None:
     f_oracle = jax.jit(lambda q, k, v: ref.ref_swa_attention(
         q, k, v, window=256))
     us_o = time_us(lambda: jax.block_until_ready(f_oracle(q, k, vv)))
-    out = ops.swa_attention(q, k, vv, window=256)
+    out = ops.swa_attention(q, k, vv, window=256, interpret=True)
     err = float(jnp.abs(out - f_oracle(q, k, vv)).max())
     emit("kernel/swa-1k", us_o,
          f"oracle_us={us_o:.0f} kernel_maxerr={err:.1e} window=256")

@@ -15,8 +15,8 @@ Two engines with one interface (:class:`TensorStore`):
   request into equal stripes across devices and issuing positional I/O
   (``os.pwrite``/``os.pread``) from a worker-thread pool — the
   libaio/io_uring analogue.  Striping subsumes software RAID-0, and no
-  filesystem metadata is touched on the data path (the region file's blocks
-  are allocated once, up front).
+  filesystem metadata is touched on the data path (the region file is
+  sized once, up front, or left to grow when no capacity is given).
 
 Both engines count bytes moved (the paper's Fig. 20 I/O-volume metric) and
 wall-clock per op (Fig. 14 latency/bandwidth benchmark).
@@ -25,6 +25,7 @@ wall-clock per op (Fig. 14 latency/bandwidth benchmark).
 from __future__ import annotations
 
 import os
+import shutil
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor, Future
@@ -38,6 +39,32 @@ LBA_ALIGN = 4096  # logical-block alignment for direct I/O
 def _as_bytes(arr: np.ndarray) -> np.ndarray:
     """uint8 view of a contiguous array (memoryview chokes on bfloat16)."""
     return np.ascontiguousarray(arr).reshape(-1).view(np.uint8)
+
+
+def filesystem_info(path: str) -> dict:
+    """Where a store root lives: its mount point, filesystem type and free
+    bytes.  A store on ``tmpfs`` is host DRAM, not SSD, so every host-memory
+    and I/O reading taken over it has to be read with this beside it.
+    ``path`` must exist; the type is ``"unknown"`` where ``/proc/mounts``
+    cannot be read."""
+    real = os.path.realpath(path)
+    mount, fstype = "/", "unknown"
+    try:
+        with open("/proc/mounts") as f:
+            lines = f.readlines()
+    except OSError:
+        lines = []
+    best = -1
+    for line in lines:
+        fields = line.split()
+        if len(fields) < 3:
+            continue
+        mnt = fields[1].replace("\\040", " ")
+        inside = real == mnt or real.startswith(mnt.rstrip("/") + "/")
+        if inside and len(mnt) > best:
+            best, mount, fstype = len(mnt), mnt, fields[2]
+    return {"path": real, "mount": mount, "fstype": fstype,
+            "free_bytes": shutil.disk_usage(real).free}
 
 
 @dataclass
@@ -225,7 +252,7 @@ class _LocationAllocator:
     updated in place thereafter — training-state I/O never frees).
     """
 
-    def __init__(self, n_devices: int, capacity: int) -> None:
+    def __init__(self, n_devices: int, capacity: int | None) -> None:
         self._next = [0] * n_devices   # guarded-by: _lock
         self._capacity = capacity
         self._lock = threading.Lock()
@@ -234,7 +261,8 @@ class _LocationAllocator:
         aligned = ((nbytes + LBA_ALIGN - 1) // LBA_ALIGN) * LBA_ALIGN
         with self._lock:
             off = self._next[device]
-            if off + aligned > self._capacity:
+            if self._capacity is not None and \
+                    off + aligned > self._capacity:
                 raise IOError(
                     f"device {device} full: need {aligned} B at {off}, "
                     f"capacity {self._capacity} B")
@@ -249,14 +277,16 @@ class DirectNVMeEngine(TensorStore):
     ----------
     root: directory where the raw 'device' region files live.
     n_devices: stripe width (the paper stripes across SSDs instead of RAID-0).
-    device_capacity: bytes preallocated per device region.
+    device_capacity: bytes preallocated per device region; ``None`` leaves
+        the region unbounded — it grows as tensors are placed, and a full
+        disk surfaces as the ``OSError`` of the failing write.
     n_workers: I/O threads (the paper's multi-threaded AIO submission).
     min_stripe: don't split requests below this size — small tensors go to a
         single device, avoiding per-stripe overhead.
     """
 
     def __init__(self, root: str, *, n_devices: int = 2,
-                 device_capacity: int = 1 << 30, n_workers: int = 4,
+                 device_capacity: int | None = 1 << 30, n_workers: int = 4,
                  min_stripe: int = 1 << 20) -> None:
         super().__init__()
         self.root = root
@@ -267,7 +297,8 @@ class DirectNVMeEngine(TensorStore):
         for d in range(n_devices):
             path = os.path.join(root, f"nvme{d}.raw")
             fd = os.open(path, os.O_RDWR | os.O_CREAT, 0o644)
-            os.ftruncate(fd, device_capacity)  # preallocate the region once
+            if device_capacity is not None:
+                os.ftruncate(fd, device_capacity)  # preallocate once
             self._fds.append(fd)
         self._alloc = _LocationAllocator(n_devices, device_capacity)
         # tensor-location dictionary: key -> (dtype, shape, [extents])

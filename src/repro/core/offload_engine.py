@@ -476,8 +476,11 @@ def memascend_policy(root: str, *, bf16_optimizer: bool = False,
         allocator_cls=AlignmentFreeAllocator,
         pool_cls=AdaptiveBufferPool,
         fused_overflow=True,
+        # unbounded regions: the store holds whatever the model needs
+        # (a published-size model outgrows any fixed preallocation)
         store_factory=lambda r=root: DirectNVMeEngine(
-            os.path.join(r, "raw_store"), n_devices=n_devices),
+            os.path.join(r, "raw_store"), n_devices=n_devices,
+            device_capacity=None),
         adam=AdamConfig(**adam_kw),
     )
 

@@ -562,6 +562,9 @@ class OffloadSession:
 
         self._plans: dict[str, StreamPlan] = {}
         self.metrics: dict = {}
+        # devices holding the last plan run's jitted output (loss or
+        # logits): how a caller confirms the blocks ran on the accelerator
+        self.output_devices: frozenset = frozenset()
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -1392,6 +1395,9 @@ class OffloadSession:
         except BaseException:
             self._abort_execute(state)
             raise
+        out = state.loss if state.loss is not None else state.logits
+        if out is not None:
+            self.output_devices = frozenset(out.devices())
         return state
 
     def _abort_execute(self, state: _ExecState) -> None:

@@ -5,8 +5,10 @@
 * :mod:`swa_attention` — banded flash attention for the long_500k shape.
 
 ``ops`` holds jitted wrappers; ``ref`` the pure-jnp oracles the tests sweep
-against.  On this CPU container the kernels run in interpret mode; BlockSpec
-tiling targets TPU (8,128) fp32 tiles and MXU-aligned matmul dims.
+against.  The wrappers lower to Mosaic unless the caller passes
+``interpret=True``, as the CPU tests do; ``tests/test_tpu_compile.py``
+compiles them for a described v5e chip.  BlockSpec tiling targets TPU
+(8,128) 32-bit tiles and MXU-aligned matmul dims.
 """
 
 from . import ops, ref

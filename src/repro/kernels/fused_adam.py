@@ -50,7 +50,7 @@ def _adam_kernel(step_ref, p_ref, g_ref, m_ref, v_ref,
 
 def fused_adam_pallas(p, g, m, v, step, *, lr=1e-4, beta1=0.9, beta2=0.999,
                       eps=1e-8, weight_decay=0.0, out_dtype=jnp.bfloat16,
-                      block_m: int = DEFAULT_BLOCK_M, interpret: bool = True):
+                      block_m: int = DEFAULT_BLOCK_M, interpret: bool = False):
     """One fused AdamW step.  All of p/g/m/v are fp32, any common shape.
 
     Returns (p_new, m_new, v_new, w16).

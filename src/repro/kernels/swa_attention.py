@@ -82,7 +82,7 @@ def _swa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def swa_attention_pallas(q, k, v, *, window: int = 0, causal: bool = True,
                          block_q: int = DEFAULT_BLOCK_Q,
                          block_k: int = DEFAULT_BLOCK_K,
-                         interpret: bool = True):
+                         interpret: bool = False):
     """Banded attention.  q: (B, H, S, D); k, v: (B, KH, S, D); KH | H.
 
     ``window=0`` means no band limit (plain causal flash attention).

@@ -16,19 +16,19 @@ from repro.kernels import ops, ref
 @pytest.mark.parametrize("n", [1, 127, 128, 129, 65_536, 100_001])
 def test_overflow_shape_dtype_sweep(dtype, n, rng):
     x = jnp.asarray(rng.standard_normal(n), dtype)
-    assert bool(ops.overflow_check(x)) == bool(ref.ref_overflow_check(x))
+    assert bool(ops.overflow_check(x, interpret=True)) == bool(ref.ref_overflow_check(x))
     x = x.at[n // 2].set(jnp.inf)
-    assert bool(ops.overflow_check(x))
+    assert bool(ops.overflow_check(x, interpret=True))
     x = x.at[n // 2].set(jnp.nan)
-    assert bool(ops.overflow_check(x))
+    assert bool(ops.overflow_check(x, interpret=True))
 
 
 @pytest.mark.parametrize("shape", [(4, 4), (3, 5, 7), (2, 2, 2, 2)])
 def test_overflow_nd_shapes(shape, rng):
     x = jnp.asarray(rng.standard_normal(shape), jnp.float32)
-    assert not bool(ops.overflow_check(x))
+    assert not bool(ops.overflow_check(x, interpret=True))
     x = x.reshape(-1).at[0].set(-jnp.inf).reshape(shape)
-    assert bool(ops.overflow_check(x))
+    assert bool(ops.overflow_check(x, interpret=True))
 
 
 @settings(max_examples=40, deadline=None)
@@ -45,7 +45,8 @@ def test_overflow_property(n, pos, kind, block_m):
     elif kind == "max":
         x[int(pos * (n - 1))] = np.finfo(np.float32).max  # must NOT trigger
     expected = kind in ("inf", "-inf", "nan")
-    got = bool(ops.overflow_check(jnp.asarray(x), block_m=block_m))
+    got = bool(ops.overflow_check(jnp.asarray(x), block_m=block_m,
+                                  interpret=True))
     assert got == expected
 
 
@@ -61,7 +62,7 @@ def test_adam_shape_step_sweep(shape, step, rng):
     m = jnp.asarray(rng.standard_normal(shape) * 0.1, jnp.float32)
     v = jnp.asarray(np.abs(rng.standard_normal(shape)) * 0.01, jnp.float32)
     kw = dict(lr=3e-3, weight_decay=0.05)
-    out_k = ops.fused_adam(p, g, m, v, step, **kw)
+    out_k = ops.fused_adam(p, g, m, v, step, interpret=True, **kw)
     out_r = ref.ref_fused_adam(p, g, m, v, step, **kw)
     for a, b in zip(out_k[:3], out_r[:3], strict=True):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -79,7 +80,8 @@ def test_adam_multi_step_trajectory(rng):
     pr, mr, vr = p, m, v
     for t in range(1, 6):
         g = g0 * (0.9 ** t)
-        p, m, v, _ = ops.fused_adam(p, g, m, v, t, lr=1e-2)
+        p, m, v, _ = ops.fused_adam(p, g, m, v, t, lr=1e-2,
+                                    interpret=True)
         pr, mr, vr, _ = ref.ref_fused_adam(pr, g, mr, vr, t, lr=1e-2)
     np.testing.assert_allclose(np.asarray(p), np.asarray(pr), rtol=1e-4,
                                atol=1e-6)
@@ -95,7 +97,8 @@ def test_adam_property(n, lr, step, seed):
     p = jnp.asarray(rng.standard_normal(n), jnp.float32)
     g = jnp.asarray(rng.standard_normal(n), jnp.float32)
     m = jnp.zeros(n); v = jnp.zeros(n)
-    p2, m2, v2, w16 = ops.fused_adam(p, g, m, v, step, lr=lr)
+    p2, m2, v2, w16 = ops.fused_adam(p, g, m, v, step, lr=lr,
+                                     interpret=True)
     pr, mr, vr, _ = ref.ref_fused_adam(p, g, m, v, step, lr=lr)
     np.testing.assert_allclose(np.asarray(p2), np.asarray(pr), rtol=1e-4,
                                atol=1e-7)
@@ -115,7 +118,8 @@ def test_swa_sweep(dtype, h, kh, window, rng):
     q = jnp.asarray(rng.standard_normal((b, h, s, d)), dtype)
     k = jnp.asarray(rng.standard_normal((b, kh, s, d)), dtype)
     v = jnp.asarray(rng.standard_normal((b, kh, s, d)), dtype)
-    out = ops.swa_attention(q, k, v, window=window, block_q=64, block_k=64)
+    out = ops.swa_attention(q, k, v, window=window, block_q=64, block_k=64,
+                            interpret=True)
     expected = ref.ref_swa_attention(q, k, v, window=window)
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -128,7 +132,7 @@ def test_swa_non_causal(rng):
     k = jnp.asarray(rng.standard_normal((b, h, s, d)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((b, h, s, d)), jnp.float32)
     out = ops.swa_attention(q, k, v, window=0, causal=False, block_q=64,
-                            block_k=64)
+                            block_k=64, interpret=True)
     expected = ref.ref_swa_attention(q, k, v, window=0, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                atol=2e-5)
@@ -139,8 +143,10 @@ def test_swa_window_equals_full_when_window_ge_seq(rng):
     q = jnp.asarray(rng.standard_normal((b, h, s, d)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((b, h, s, d)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((b, h, s, d)), jnp.float32)
-    full = ops.swa_attention(q, k, v, window=0, block_q=64, block_k=64)
-    wide = ops.swa_attention(q, k, v, window=s, block_q=64, block_k=64)
+    full = ops.swa_attention(q, k, v, window=0, block_q=64, block_k=64,
+                             interpret=True)
+    wide = ops.swa_attention(q, k, v, window=s, block_q=64, block_k=64,
+                             interpret=True)
     np.testing.assert_allclose(np.asarray(full), np.asarray(wide), atol=1e-6)
 
 
@@ -157,7 +163,8 @@ def test_swa_block_shape_invariance(s, window, blocks, seed):
     k = jnp.asarray(rng.standard_normal((b, h, s, d)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((b, h, s, d)), jnp.float32)
     bq, bk = blocks
-    out = ops.swa_attention(q, k, v, window=window, block_q=bq, block_k=bk)
+    out = ops.swa_attention(q, k, v, window=window, block_q=bq, block_k=bk,
+                            interpret=True)
     expected = ref.ref_swa_attention(q, k, v, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                atol=3e-5)

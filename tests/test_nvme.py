@@ -72,6 +72,36 @@ def test_capacity_exhaustion(tmp_store_root):
     eng.close()
 
 
+def test_memascend_store_outgrows_a_fixed_region(tmp_store_root, rng):
+    """The memascend preset's store holds what a published-size model
+    needs (Qwen2.5-0.5B: about 9.5 GB): its regions grow as tensors are
+    placed instead of failing past a fixed preallocation."""
+    from repro.core import OffloadPolicy
+    policy = OffloadPolicy.preset("memascend").with_store(
+        tmp_store_root).build()
+    eng = policy.store_factory()
+    try:
+        eng._plan_extents(3 << 30)      # placed, never written: sparse
+        x = rng.standard_normal(1000).astype(np.float32)
+        eng.write("past_3GiB", x)
+        _, _, extents = eng._locations["past_3GiB"]
+        assert extents[0].offset >= (3 << 30) // eng.n_devices
+        np.testing.assert_array_equal(
+            eng.read_new("past_3GiB", np.float32, x.shape), x)
+    finally:
+        eng.close()
+
+
+def test_filesystem_info_names_the_mount(tmp_store_root):
+    import os
+    from repro.core.nvme import filesystem_info
+    os.makedirs(tmp_store_root)
+    info = filesystem_info(tmp_store_root)
+    assert info["path"] == os.path.realpath(tmp_store_root)
+    assert info["path"].startswith(info["mount"].rstrip("/") + "/")
+    assert info["fstype"] and info["free_bytes"] > 0
+
+
 def test_size_change_rejected(tmp_store_root):
     eng = DirectNVMeEngine(tmp_store_root, n_devices=1,
                            device_capacity=1 << 24)

@@ -257,7 +257,7 @@ def test_kv_page_spill_refill_round_trip(tmp_store_root):
 
 
 def test_kv_prefetch_window_overlaps_and_hits(tmp_store_root):
-    kv, pool, _store = _kv_fixture(tmp_store_root, resident=3)
+    kv, pool, store = _kv_fixture(tmp_store_root, resident=3)
     z = np.zeros((1, 4, 1, 2), np.float32)
     for u in ("a", "b", "c"):
         kv.write_prefill(u, z, z)              # all spilled (keep budget 1)
@@ -269,6 +269,7 @@ def test_kv_prefetch_window_overlaps_and_hits(tmp_store_root):
     assert kv.stats.prefetch_refills == 1
     kv.close()
     assert pool.in_use_payload == 0
+    store.close()      # joins the async-read threads the prefetch started
 
 
 def test_kv_cache_full_and_length_bounds(tmp_store_root):

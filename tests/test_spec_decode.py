@@ -222,6 +222,7 @@ def test_rollback_with_inflight_refill_future(tmp_store_root):
     assert (kg[0, 2:] == 0).all()              # refill did not resurrect
     kv.close()
     assert pool.in_use_payload == 0
+    store.close()      # joins the async-read threads the refill started
 
 
 def test_rollback_validation(tmp_store_root):
