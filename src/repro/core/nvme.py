@@ -19,7 +19,9 @@ Two engines with one interface (:class:`TensorStore`):
   sized once, up front, or left to grow when no capacity is given).
 
 Both engines count bytes moved (the paper's Fig. 20 I/O-volume metric) and
-wall-clock per op (Fig. 14 latency/bandwidth benchmark).
+wall-clock per op (Fig. 14 latency/bandwidth benchmark), and span each
+blocking read and write (``store.read``, ``store.write``) on the profiler's
+host planes over the interval that ledger times.
 """
 
 from __future__ import annotations
@@ -32,6 +34,7 @@ from concurrent.futures import ThreadPoolExecutor, Future
 from dataclasses import dataclass, field
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 LBA_ALIGN = 4096  # logical-block alignment for direct I/O
 
@@ -197,7 +200,7 @@ class FilesystemEngine(TensorStore):
         t0 = time.perf_counter()
         # open -> allocate blocks -> write -> metadata update: the whole
         # filesystem path, per tensor, per iteration.
-        with open(self._path(key), "wb") as f:
+        with TraceAnnotation("store.write"), open(self._path(key), "wb") as f:
             f.write(_as_bytes(data))
             f.flush()
             if self.fsync:
@@ -209,7 +212,7 @@ class FilesystemEngine(TensorStore):
     def read(self, key: str, out: np.ndarray) -> np.ndarray:
         t0 = time.perf_counter()
         path = self._path(key)
-        with open(path, "rb") as f:
+        with TraceAnnotation("store.read"), open(path, "rb") as f:
             n = f.readinto(_as_bytes(out))
         if n != out.nbytes:
             raise IOError(f"short read for {key}: {n} != {out.nbytes}")
@@ -377,7 +380,8 @@ class DirectNVMeEngine(TensorStore):
         data = np.ascontiguousarray(data)
         extents = self._extents_for(key, data)
         t0 = time.perf_counter()
-        self._rw_striped("w", extents, memoryview(_as_bytes(data)))
+        with TraceAnnotation("store.write"):
+            self._rw_striped("w", extents, memoryview(_as_bytes(data)))
         self.stats.record("w", data.nbytes, time.perf_counter() - t0)
 
     def read(self, key: str, out: np.ndarray) -> np.ndarray:
@@ -390,7 +394,8 @@ class DirectNVMeEngine(TensorStore):
         if total != out.nbytes:
             raise ValueError(f"read size mismatch for {key}: {out.nbytes} vs {total}")
         t0 = time.perf_counter()
-        self._rw_striped("r", extents, memoryview(_as_bytes(out)))
+        with TraceAnnotation("store.read"):
+            self._rw_striped("r", extents, memoryview(_as_bytes(out)))
         self.stats.record("r", out.nbytes, time.perf_counter() - t0)
         return out
 

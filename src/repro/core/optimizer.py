@@ -49,6 +49,8 @@ from dataclasses import dataclass
 import numpy as np
 import ml_dtypes
 
+from .overlap import OverlapStats
+
 BF16 = np.dtype(ml_dtypes.bfloat16)
 F32 = np.dtype(np.float32)
 
@@ -210,16 +212,22 @@ class OffloadedAdam:
     ``write_guard`` (optional, set by the session) is called with the base
     key before the refreshed compute weights are written — the stale-read
     guard asserting no prefetched read of those weights is still in flight.
+
+    ``stats`` (the session passes its own) receives the stage's busy
+    counters and spans: state reads, arena waits and write-backs (see
+    :meth:`OverlapStats.timed`).
     """
 
     MASTER, M, V, COMPUTE = ".master", ".m", ".v", ".compute"
 
     def __init__(self, store, cfg: AdamConfig, *, tracker=None,
-                 component: str = "optimizer_stream") -> None:
+                 component: str = "optimizer_stream",
+                 stats: OverlapStats | None = None) -> None:
         from .memory_tracker import GLOBAL_TRACKER
         self.store = store
         self.cfg = cfg
         self.tracker = tracker or GLOBAL_TRACKER
+        self.stats = stats if stats is not None else OverlapStats()
         self.component = component
         self.step_count = 0
         self.subgroups: dict[str, SubgroupMeta] = {}
@@ -334,20 +342,24 @@ class OffloadedAdam:
         meta = self.subgroups[key]
         sd = self.cfg.state_np_dtype
         arena = self._ensure_arena()
-        buf = arena.acquire()
+        with self.stats.timed("adam_arena_wait_seconds"):
+            buf = arena.acquire()
         try:
-            n = meta.size
-            master, m, v, scratch = arena.views(buf, n)
-            targets = [(self.MASTER, master), (self.M, m), (self.V, v)]
-            if sd == F32:
-                for skey, out in targets:
-                    self.store.read(key + skey, out)
-            else:
-                # read at state precision into the scratch, upcast in place
-                halves = self._state_scratch(scratch, n)
-                for (skey, out), half in zip(targets, halves, strict=True):
-                    self.store.read(key + skey, half)
-                    out[:] = half
+            with self.stats.timed("adam_read_seconds"):
+                n = meta.size
+                master, m, v, scratch = arena.views(buf, n)
+                targets = [(self.MASTER, master), (self.M, m), (self.V, v)]
+                if sd == F32:
+                    for skey, out in targets:
+                        self.store.read(key + skey, out)
+                else:
+                    # read at state precision into the scratch, upcast in
+                    # place
+                    halves = self._state_scratch(scratch, n)
+                    for (skey, out), half in zip(targets, halves,
+                                                 strict=True):
+                        self.store.read(key + skey, half)
+                        out[:] = half
             return StagedSubgroup(key, buf, master, m, v,
                                   io_read=3 * n * sd.itemsize)
         except BaseException:
@@ -439,7 +451,7 @@ class OffloadedAdam:
         try:
             pool = self._pool()
             for skey, src in batch:
-                writes.append(pool.submit(self.store.write, key + skey, src))
+                writes.append(pool.submit(self._write_back, key + skey, src))
         except BaseException:
             # submit itself failed (e.g. executor shut down mid-teardown):
             # the buffer must still come back — via the already-submitted
@@ -455,6 +467,10 @@ class OffloadedAdam:
         for fut in writes:
             fut.add_done_callback(_one_landed)
         return done
+
+    def _write_back(self, key: str, src: np.ndarray) -> None:  # thread: store-worker
+        with self.stats.timed("adam_write_seconds"):
+            self.store.write(key, src)
 
     def commit_subgroup(self, staged: StagedSubgroup, *,
                         return_compute: bool = False

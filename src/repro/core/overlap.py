@@ -56,15 +56,31 @@ Thread contract (who may call what)
   releasable by the live executor.
 * :class:`OverlapStats` plain fields are executor-thread-only; counters
   accrued on worker threads go through
-  :meth:`OverlapStats.add_worker_seconds`, which locks.
+  :meth:`OverlapStats.add_worker_seconds` or :meth:`OverlapStats.timed`,
+  which lock.
+
+Spans
+-----
+
+:meth:`OverlapStats.timed` is the pipeline's one tracing mechanism: it
+opens a :class:`jax.profiler.TraceAnnotation` named by :data:`SPANS` and
+adds the same interval to the counter.  The spans land on the profiler's
+host planes, on the device trace's clock, so a trace names what each host
+thread did in every device idle gap; with no trace running they cost
+about a microsecond.  Only work is spanned, never an executor wait: a
+wait span would cover each gap whole and hide the work it waited on.
 """
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
+import time
 from concurrent.futures import Future
 from dataclasses import dataclass, field
+
+from jax.profiler import TraceAnnotation
 
 
 # Device-slot class bounding staged activation-checkpoint H2Ds (train
@@ -79,6 +95,20 @@ ACT_CLASS = "__act__"
 # current block_moe plus one being staged for the next MoE unit — the
 # same rotation and deadlock-freedom argument as ACT_CLASS.
 EXPERT_CLASS = "__expert__"
+
+# Counter -> profiler span of every interval OverlapStats.timed measures
+# (docs/METRICS.md, "Spans").  No name starts with "bench.": the
+# benchmark's trace reduction reserves those for its own spans.
+SPANS = {
+    "adam_read_seconds": "offload.adam.read",
+    "adam_arena_wait_seconds": "offload.adam.arena_wait",
+    "adam_update_seconds": "offload.adam.update",
+    "adam_commit_wait_seconds": "offload.adam.commit_wait",
+    "adam_write_seconds": "offload.adam.write",
+    "optim_prefetch_wait_seconds": "offload.adam.prefetch_wait",
+    "grad_d2h_seconds": "offload.gradwrite.d2h",
+    "h2d_copy_seconds": "offload.h2d.copy",
+}
 
 
 def done_future(value=None) -> Future:
@@ -242,7 +272,8 @@ class DeviceSlots:
 
 @dataclass
 class OverlapStats:
-    """Compute-thread-visible stall counters for the overlapped legs.
+    """Compute-thread-visible stall counters for the overlapped legs, and
+    busy counters of the host pipeline's work.
 
     ``h2d_wait_seconds`` is what :class:`~repro.core.swapper.SwapStats.
     wait_seconds` is to SSD reads: the time the executor actually blocked
@@ -253,12 +284,13 @@ class OverlapStats:
     blocking at a KVReadOp for a staged KV window (page refill waits move
     onto the staging worker and into the KV cache's own wait ledger).
 
-    Most fields are mutated by the single executor thread only.  The two
-    worker-side counters — ``optim_prefetch_wait_seconds`` (the optimizer
-    worker blocked on a state-prefetch future inside the Adam stage) and
-    ``overflow_screen_seconds`` (per-region Inf/NaN screens, paid on the
-    gradient-writer thread under full overlap) — are accumulated through
-    :meth:`add_worker_seconds`, which locks.
+    Most fields are mutated by the single executor thread only.  The
+    worker-side counters — the :data:`SPANS` intervals (the Adam stage's
+    legs, the gradient writer's D2H, the H2D copies), ``overflow_screen_
+    seconds`` (per-region Inf/NaN screens, paid on the gradient-writer
+    thread under full overlap) and ``act_write_failures`` — are
+    accumulated through :meth:`timed`, :meth:`add_worker_seconds` or
+    :meth:`bump`, which lock.
     """
 
     fetch_seconds: float = 0.0  # total FetchOp blocking: read wait + H2D,
@@ -278,8 +310,6 @@ class OverlapStats:
     #                                     inline D2H + store write)
     act_fetch_wait_seconds: float = 0.0  # executor blocked at an ActFetchOp
     #                                      for a staged checkpoint
-    act_stage_gets: int = 0     # ActFetchOps served from the staging pipeline
-    act_stage_hits: int = 0     # checkpoint staged when the ActFetchOp asked
     expert_stage_gets: int = 0  # ExpertFetchOps served from the pipeline
     expert_stage_hits: int = 0  # routed set covered by the prestaged stack
     expert_fetch_wait_seconds: float = 0.0  # executor blocked at an
@@ -287,33 +317,52 @@ class OverlapStats:
     expert_fetch_bytes: int = 0  # expert bytes copied into H2D stacks
     #                              (routed-only vs all-resident ledger);
     #                              accrued via bump() on the staging worker
-    optim_prefetch_wait_seconds: float = 0.0  # Adam blocked on staged state
-    overflow_screen_seconds: float = 0.0      # per-region Inf/NaN screens
-    act_save_seconds: float = 0.0  # D2H + store write on the writer thread
-    act_write_failures: int = 0    # SSD act writes that fell back to host
+    # Worker-thread counters (guarded-by _lock: timed, add_worker_seconds,
+    # bump).  The SPANS busy counters' intervals: docs/METRICS.md.
+    optim_prefetch_wait_seconds: float = 0.0  # guarded-by: _lock
+    overflow_screen_seconds: float = 0.0      # guarded-by: _lock
+    act_write_failures: int = 0   # guarded-by: _lock; act writes to host
+    adam_read_seconds: float = 0.0            # guarded-by: _lock
+    adam_arena_wait_seconds: float = 0.0      # guarded-by: _lock
+    adam_update_seconds: float = 0.0          # guarded-by: _lock
+    adam_commit_wait_seconds: float = 0.0     # guarded-by: _lock
+    adam_write_seconds: float = 0.0           # guarded-by: _lock
+    grad_d2h_seconds: float = 0.0             # guarded-by: _lock
+    h2d_copy_seconds: float = 0.0             # guarded-by: _lock
     _lock: threading.Lock = field(default_factory=threading.Lock,
                                   repr=False, compare=False)
 
-    def add_worker_seconds(self, name: str, dt: float) -> None:
+    def add_worker_seconds(self, name: str, dt: float) -> None:  # thread: any
         """Accumulate a worker-thread stall into ``name`` (lock-guarded —
         the Adam stage and the gradient writer report from their own
         threads while the executor reads snapshots)."""
         with self._lock:
             setattr(self, name, getattr(self, name) + dt)
 
-    def bump(self, name: str, n: int = 1) -> None:
+    @contextlib.contextmanager
+    def timed(self, name: str):  # thread: any
+        """Span and count the enclosed work: a profiler span named
+        ``SPANS[name]`` and, on exit (raised or not), the elapsed time
+        added to counter ``name`` — both over the same interval."""
+        with TraceAnnotation(SPANS[name]):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.add_worker_seconds(name, time.perf_counter() - t0)
+
+    def bump(self, name: str, n: int = 1) -> None:  # thread: any
         """Increment a worker-thread counter (lock-guarded — e.g. the
         gradient writer recording an act-write SSD fallback)."""
         with self._lock:
             setattr(self, name, getattr(self, name) + n)
 
-    def snapshot(self) -> dict:
+    def snapshot(self) -> dict:  # thread: any
         with self._lock:
             worker = {
-                "optim_prefetch_wait_seconds": self.optim_prefetch_wait_seconds,
                 "overflow_screen_seconds": self.overflow_screen_seconds,
-                "act_save_seconds": self.act_save_seconds,
-                "act_write_failures": self.act_write_failures}
+                "act_write_failures": self.act_write_failures,
+                **{name: getattr(self, name) for name in SPANS}}
         return {"fetch_seconds": self.fetch_seconds,
                 "h2d_gets": self.h2d_gets, "h2d_hits": self.h2d_hits,
                 "h2d_wait_seconds": self.h2d_wait_seconds,
@@ -324,8 +373,6 @@ class OverlapStats:
                 "optim_gate_seconds": self.optim_gate_seconds,
                 "act_save_wait_seconds": self.act_save_wait_seconds,
                 "act_fetch_wait_seconds": self.act_fetch_wait_seconds,
-                "act_stage_gets": self.act_stage_gets,
-                "act_stage_hits": self.act_stage_hits,
                 "expert_stage_gets": self.expert_stage_gets,
                 "expert_stage_hits": self.expert_stage_hits,
                 "expert_fetch_wait_seconds": self.expert_fetch_wait_seconds,

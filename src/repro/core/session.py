@@ -98,8 +98,8 @@ from .loss_scale import DynamicLossScaler
 from .memory_tracker import MemoryTracker
 from .optimizer import OffloadedAdam
 from .overflow import check_region, flat_overflow_check
-from .overlap import (ACT_CLASS, EXPERT_CLASS, DeviceSlots, OverlapStats,
-                      SerialWorker, done_future)
+from .overlap import (ACT_CLASS, EXPERT_CLASS, SPANS, DeviceSlots,
+                      OverlapStats, SerialWorker, done_future)
 from .paged import ExpertPageCache
 from .stream_plan import (ActFetchOp, ActSaveOp, ComputeOp, ExpertFetchOp,
                           ExpertReleaseOp, FetchOp, GradWriteOp, KVReadOp,
@@ -449,7 +449,8 @@ class OffloadSession:
         # Register every parameter.  Train mode seeds master weights + Adam
         # moments on the store; serve mode writes only compute weights.
         self.optimizer = (OffloadedAdam(self.store, policy.adam,
-                                        tracker=self.tracker)
+                                        tracker=self.tracker,
+                                        stats=self._ostats)
                           if mode == "train" else None)
         if self.optimizer is not None:
             # stale-read guard on the Adam commit's compute-weight write:
@@ -702,8 +703,9 @@ class OffloadSession:
         contents until the copy has landed; it blocks the *staging* worker
         (or, in sync mode, the compute thread that was going to wait
         anyway), never an overlapped compute."""
-        arr = jnp.array(host_view, copy=True)
-        arr.block_until_ready()
+        with self._ostats.timed("h2d_copy_seconds"):
+            arr = jnp.array(host_view, copy=True)
+            arr.block_until_ready()
         return arr
 
     def _submit_h2d(self, unit_name: str, state: _ExecState) -> None:
@@ -887,33 +889,28 @@ class OffloadSession:
         the store and free the host copy.  A failed SSD write degrades
         gracefully: the host copy stays live (tracked) and the checkpoint
         serves from the host tier — no data loss, no raised step."""
-        t0 = time.perf_counter()
+        host = np.asarray(rec.value)   # D2H
+        handle = self.tracker.alloc("activation_checkpoints",
+                                    host.nbytes, tag="block_input")
         try:
-            host = np.asarray(rec.value)   # D2H
-            handle = self.tracker.alloc("activation_checkpoints",
-                                        host.nbytes, tag="block_input")
-            try:
-                if tier == "ssd":
-                    try:
-                        self.store.write(self._act_key(rec.unit, host.nbytes),
-                                         host)
-                    except Exception:
-                        self._ostats.bump("act_write_failures")
-                    else:
-                        self.tracker.free(handle)
-                        rec.shape, rec.np_dtype = host.shape, host.dtype
-                        rec.value, rec.handle = None, None
-                        rec.tier = "ssd"
-                        return
-                rec.value, rec.handle = host, handle
-                rec.tier = "host"
-            except BaseException:
-                # rec stays device-tier; the abort path discards it safely
-                self.tracker.free(handle)
-                raise
-        finally:
-            self._ostats.add_worker_seconds("act_save_seconds",
-                                            time.perf_counter() - t0)
+            if tier == "ssd":
+                try:
+                    self.store.write(self._act_key(rec.unit, host.nbytes),
+                                     host)
+                except Exception:
+                    self._ostats.bump("act_write_failures")
+                else:
+                    self.tracker.free(handle)
+                    rec.shape, rec.np_dtype = host.shape, host.dtype
+                    rec.value, rec.handle = None, None
+                    rec.tier = "ssd"
+                    return
+            rec.value, rec.handle = host, handle
+            rec.tier = "host"
+        except BaseException:
+            # rec stays device-tier; the abort path discards it safely
+            self.tracker.free(handle)
+            raise
 
     def _act_issue_ahead(self, state: _ExecState) -> None:  # thread: executor
         """Issue half of upcoming ActFetchOps: start SSD reads + H2D
@@ -1036,7 +1033,6 @@ class OffloadSession:
         t0 = time.perf_counter()
         staged = state.act_stage.pop(unit, None)
         if staged is not None:
-            hit = staged.done()
             try:
                 arr = staged.result()
             finally:
@@ -1045,8 +1041,6 @@ class OffloadSession:
                 if rec.handle is not None:
                     self.tracker.free(rec.handle)
                     rec.handle = None
-            self._ostats.act_stage_gets += 1
-            self._ostats.act_stage_hits += int(hit)
             rec.value, rec.tier, rec.slot = arr, "ready", True
         elif unit in state.act_reads:
             read_fut, buf, handle = state.act_reads.pop(unit)
@@ -1666,10 +1660,11 @@ class OffloadSession:
         if gate is not None:
             gate.result()   # step k-1's Adam must consume flat[unit] first
         _unit, meta = self._units[unit_name]
-        for key in meta:
-            off, size, shape = self._flat_offsets[f"{unit_name}/{key}"]
-            g = np.asarray(grads[key], dtype=np.float32).reshape(-1)  # D2H
-            self.flat[off:off + size] = g
+        with self._ostats.timed("grad_d2h_seconds"):
+            for key in meta:
+                off, size, shape = self._flat_offsets[f"{unit_name}/{key}"]
+                g = np.asarray(grads[key], dtype=np.float32).reshape(-1)  # D2H
+                self.flat[off:off + size] = g
         if self._screen_regions:
             self._screen_unit_region(unit_name)
 
@@ -1784,15 +1779,24 @@ class OffloadSession:
         return copy is materialized — the store holds it)."""
         _unit, meta = self._units[unit_name]
         for key in meta:
-            skey = f"{unit_name}/{key}"
-            staged = self.optimizer.issue_subgroup(skey)
+            staged = self.optimizer.issue_subgroup(f"{unit_name}/{key}")
+            commit = self._update_subgroup(staged, inv_scale)
+            with self._ostats.timed("adam_commit_wait_seconds"):
+                commit.result()
+
+    def _update_subgroup(self, staged, inv_scale: np.float32) -> Future:  # thread: executor, optim-worker
+        """The arithmetic of one staged subgroup — unscale, Adam, and the
+        commit's truncation and compute-copy casts — as one ``adam_update``
+        interval; returns the commit's write-back future.  A failed update
+        releases the staging buffer."""
+        with self._ostats.timed("adam_update_seconds"):
             try:
                 self.optimizer.compute_subgroup(
-                    staged, self._unit_grad(skey, inv_scale))
+                    staged, self._unit_grad(staged.key, inv_scale))
             except BaseException:
                 self.optimizer.discard_staged(staged)
                 raise
-            self.optimizer.commit_subgroup(staged)
+            return self.optimizer.commit_subgroup_async(staged)
 
     def _unit_grad(self, skey: str, inv_scale: np.float32) -> np.ndarray:  # thread: executor, optim-worker
         """Unscale one subgroup's gradient out of the flat buffer.
@@ -1856,23 +1860,12 @@ class OffloadSession:
                     raise RuntimeError(   # issue order == work order
                         f"adam pipeline out of order: staged {idx}, "
                         f"expected {g}")
-                t0 = time.perf_counter()
-                try:
+                with self._ostats.timed("optim_prefetch_wait_seconds"):
                     staged = staged_fut.result()
-                finally:
-                    self._ostats.add_worker_seconds(
-                        "optim_prefetch_wait_seconds",
-                        time.perf_counter() - t0)
-                try:
-                    self.optimizer.compute_subgroup(
-                        staged, self._unit_grad(staged.key, inv_scale))
-                except BaseException:
-                    self.optimizer.discard_staged(staged)
-                    raise
-                commits.append(
-                    self.optimizer.commit_subgroup_async(staged))
-            for commit in commits:
-                commit.result()
+                commits.append(self._update_subgroup(staged, inv_scale))
+            with self._ostats.timed("adam_commit_wait_seconds"):
+                for commit in commits:
+                    commit.result()
         except BaseException as e:
             self._adam_poison = e
             self._adam_abort(commits, resume_at=hi)
@@ -1971,10 +1964,12 @@ class OffloadSession:
         o1 = self._ostats.snapshot()
         # worker-side counters: the Adam stage of step k accrues these
         # while step k+1's window runs, so (like optim_gate_s) they are
-        # attributed to the train_step whose wall-clock window they land in
-        self.metrics["optim_prefetch_wait_s"] = (
-            o1["optim_prefetch_wait_seconds"]
-            - o0["optim_prefetch_wait_seconds"])
+        # attributed to the train_step whose wall-clock window they land
+        # in.  The spanned busy counters (optim_prefetch_wait_s, adam_*_s,
+        # grad_d2h_s, h2d_copy_s; docs/METRICS.md) are reported the same way.
+        for name in SPANS:
+            self.metrics[name.removesuffix("_seconds") + "_s"] = (
+                o1[name] - o0[name])
         self.metrics["overflow_screen_s"] = (
             o1["overflow_screen_seconds"] - o0["overflow_screen_seconds"])
         # activation streaming: executor stall on checkpoint saves (gating
