@@ -7,9 +7,12 @@ through host subgroup buffers.
 
 This module provides:
 
-* :func:`adam_update` — the vectorized numpy update (our AVX analogue),
-  with bias correction and decoupled weight decay, dtype-templated like the
-  DeepSpeed C++ backend (fp32 or bf16 optimizer states).
+* :func:`adam_update` — the numpy update (our AVX analogue), with bias
+  correction and decoupled weight decay, dtype-templated like the
+  DeepSpeed C++ backend (fp32 or bf16 optimizer states).  It walks the
+  subgroup in cache-sized tiles, every pass writing into preallocated
+  float32 scratch, with the tiles spread over a small worker pool (the
+  C++ backend's OpenMP threads).
 * :class:`OffloadedAdam` — streams (master, m, v) subgroups from a
   :class:`~repro.core.nvme.TensorStore`, updates on host, writes back, and
   emits new half-precision compute weights.  Counts per-iteration I/O volume
@@ -26,7 +29,8 @@ state I/O the same way):
   **double-buffered staging arena** and read (master, m, v) into its fp32
   views (one read stream, on the state-prefetch thread),
 * :meth:`OffloadedAdam.compute_subgroup` — :func:`adam_update` in place on
-  the staged fp32 state (optimizer thread),
+  the staged fp32 state (optimizer thread, its tiles spread over the
+  optimizer's :class:`TilePool`),
 * :meth:`OffloadedAdam.commit_subgroup_async` — truncate + write back
   master/m/v and the fresh compute-precision weights on a dedicated
   single-thread write-back executor (one write stream, draining behind
@@ -42,8 +46,9 @@ so ``bench_peak_memory``'s Adam-stage numbers reflect real memory.
 
 from __future__ import annotations
 
+import os
 import threading
-from concurrent.futures import Future, ThreadPoolExecutor
+from concurrent.futures import Future, ThreadPoolExecutor, wait
 from dataclasses import dataclass
 
 import numpy as np
@@ -79,26 +84,166 @@ class AdamConfig:
         return self.state_np_dtype.itemsize
 
 
+# Entries per tile of the Adam update: 1 MiB of float32, so that a tile's
+# operands and its scratch stay in a core's cache between passes and no
+# temporary grows with the subgroup.
+TILE = 1 << 18
+# float32 scratch tiles per worker: the unscaled gradient and two temporaries
+SCRATCH_TILES = 3
+
+
+def tile_workers() -> int:
+    """Workers of the tiled update: a third of the CPUs this process may
+    run on (the pipeline's other threads keep the rest), 1 to 4."""
+    return min(4, max(1, len(os.sched_getaffinity(0)) // 3))
+
+
 def adam_update(master: np.ndarray, grad: np.ndarray, m: np.ndarray,
-                v: np.ndarray, step: int, cfg: AdamConfig) -> None:
+                v: np.ndarray, step: int, cfg: AdamConfig, *,
+                grad_scale: float | None = None,
+                pool: "TilePool | None" = None) -> None:
     """In-place Adam step on fp32 working copies.
 
-    ``master``, ``m``, ``v`` are fp32 views; callers holding bf16 state
-    upcast before and truncate after (exactly the paper's direct-truncation
-    scheme).  ``grad`` is fp32 (already unscaled).
+    ``master``, ``m``, ``v`` are contiguous fp32 arrays; callers holding
+    bf16 state upcast before and truncate after (exactly the paper's
+    direct-truncation scheme).  ``grad`` is fp32, multiplied by
+    ``grad_scale`` tile by tile when one is given (the loss-scale unscale,
+    read straight out of the gradient buffer).
+
+    The update walks the arrays in tiles of :data:`TILE` entries, each
+    pass of a tile writing into float32 scratch, so no temporary grows
+    with the subgroup.  Its tiles run on ``pool``'s workers; without one,
+    on the calling thread with scratch of its own.  The passes and their
+    order are those of the whole-array formula
+    (``m = m*b1 + (1-b1)*g``, ``v = v*b2 + (1-b2)*g*g``,
+    ``update = (m/bias1) / (sqrt(v/bias2) + eps) [+ wd*master]``,
+    ``master -= lr*update``), all in float32, so the result is the same
+    bit for bit whatever the tiling.
     """
-    b1, b2 = cfg.beta1, cfg.beta2
-    m *= b1
-    m += (1.0 - b1) * grad
-    v *= b2
-    v += (1.0 - b2) * np.square(grad)
-    bias1 = 1.0 - b1 ** step
-    bias2 = 1.0 - b2 ** step
-    denom = np.sqrt(v / bias2) + cfg.eps
-    update = (m / bias1) / denom
-    if cfg.weight_decay:
-        update += cfg.weight_decay * master
-    master -= cfg.lr * update
+    flat = []
+    for name, a in (("master", master), ("m", m), ("v", v)):
+        if not a.flags.c_contiguous:
+            raise ValueError(f"adam_update needs a contiguous {name}")
+        flat.append(a.reshape(-1))
+    args = (*flat, np.reshape(grad, -1),
+            _AdamScalars.of(step, cfg, grad_scale))
+    n = master.size
+    if pool is None:
+        _update_tiles(*args, 0, n,
+                      np.empty((SCRATCH_TILES, min(n, TILE)), np.float32))
+    else:
+        pool.run(_update_tiles, args, n)
+
+
+@dataclass(frozen=True)
+class _AdamScalars:
+    """One step's constants, rounded to float32 as numpy rounds a Python
+    float meeting a float32 array."""
+
+    b1: np.float32
+    c1: np.float32      # 1 - beta1
+    b2: np.float32
+    c2: np.float32      # 1 - beta2
+    bias1: np.float32
+    bias2: np.float32
+    eps: np.float32
+    lr: np.float32
+    wd: np.float32 | None
+    scale: np.float32 | None
+
+    @classmethod
+    def of(cls, step: int, cfg: AdamConfig,
+           grad_scale: float | None) -> "_AdamScalars":
+        f = np.float32
+        b1, b2 = cfg.beta1, cfg.beta2
+        wd = cfg.weight_decay
+        return cls(f(b1), f(1.0 - b1), f(b2), f(1.0 - b2),
+                   f(1.0 - b1 ** step), f(1.0 - b2 ** step), f(cfg.eps),
+                   f(cfg.lr), f(wd) if wd else None,
+                   None if grad_scale is None else f(grad_scale))
+
+
+def _update_tiles(master: np.ndarray, m: np.ndarray, v: np.ndarray,
+                  grad: np.ndarray, k: _AdamScalars, lo: int, hi: int,
+                  scratch: np.ndarray) -> None:  # thread: executor, optim-worker, adam-tile
+    """The update of entries [lo, hi), one tile at a time, every pass
+    writing into ``master``, ``m``, ``v`` or the ``scratch`` tiles."""
+    for a in range(lo, hi, TILE):
+        b = min(a + TILE, hi)
+        w, mt, vt, g = master[a:b], m[a:b], v[a:b], grad[a:b]
+        s0, s1, s2 = (row[:b - a] for row in scratch)
+        if k.scale is not None:
+            g = np.multiply(g, k.scale, out=s0)
+        np.multiply(mt, k.b1, out=mt)
+        np.multiply(g, k.c1, out=s1)
+        np.add(mt, s1, out=mt)
+        np.multiply(vt, k.b2, out=vt)
+        np.square(g, out=s1)
+        np.multiply(s1, k.c2, out=s1)
+        np.add(vt, s1, out=vt)
+        np.divide(vt, k.bias2, out=s1)      # denom = sqrt(v/bias2) + eps
+        np.sqrt(s1, out=s1)
+        np.add(s1, k.eps, out=s1)
+        np.divide(mt, k.bias1, out=s2)      # update = (m/bias1) / denom
+        np.divide(s2, s1, out=s2)
+        if k.wd is not None:
+            np.multiply(w, k.wd, out=s1)
+            np.add(s2, s1, out=s2)
+        np.multiply(s2, k.lr, out=s2)
+        np.subtract(w, s2, out=w)
+
+
+class TilePool:
+    """Workers and float32 scratch for :func:`adam_update`'s tiles.
+
+    Worker 0 is the calling thread; ``workers - 1`` threads
+    (``offload-adam-tile``) take the others.  Each worker owns
+    :data:`SCRATCH_TILES` tiles of scratch, allocated and tracker-charged
+    once.  An update of at most one tile runs inline on the caller; a
+    larger one splits its tiles contiguously over the workers and returns
+    when all are done.  One update at a time: the caller is the
+    optimizer's single compute thread.
+    """
+
+    def __init__(self, workers: int, tile: int, tracker,
+                 component: str) -> None:
+        self.workers = workers
+        self._scratch = [np.empty((SCRATCH_TILES, tile), np.float32)
+                         for _ in range(workers)]
+        self._tracker = tracker
+        self._handle = tracker.alloc(
+            component, workers * SCRATCH_TILES * tile * 4,
+            tag="adam_tile_scratch")
+        self._threads = (ThreadPoolExecutor(
+            max_workers=workers - 1, thread_name_prefix="offload-adam-tile")
+            if workers > 1 else None)
+
+    def spread(self, n: int) -> int:  # thread: any
+        """Workers an update of ``n`` entries runs on."""
+        return min(self.workers, -(-n // TILE))
+
+    def run(self, fn, args: tuple, n: int) -> None:  # thread: executor, optim-worker
+        """``fn(*args, lo, hi, scratch)`` over [0, n) in whole tiles."""
+        k = self.spread(n)
+        if k <= 1:
+            fn(*args, 0, n, self._scratch[0])
+            return
+        tiles = -(-n // TILE)
+        cut = [i * tiles // k * TILE for i in range(k)] + [n]
+        futs = [self._threads.submit(fn, *args, cut[i], cut[i + 1],
+                                     self._scratch[i])
+                for i in range(1, k)]
+        try:
+            fn(*args, cut[0], cut[1], self._scratch[0])
+        finally:
+            wait(futs)      # no worker still writes when the caller leaves
+        for fut in futs:
+            fut.result()
+
+    def close(self) -> None:  # thread: executor
+        if self._threads is not None:
+            self._threads.shutdown(wait=True)
+        self._tracker.free(self._handle)
 
 
 @dataclass
@@ -215,7 +360,8 @@ class OffloadedAdam:
 
     ``stats`` (the session passes its own) receives the stage's busy
     counters and spans: state reads, arena waits and write-backs (see
-    :meth:`OverlapStats.timed`).
+    :meth:`OverlapStats.timed`), and the update's entry counts
+    (``adam_update_elems``, ``adam_parallel_elems``).
     """
 
     MASTER, M, V, COMPUTE = ".master", ".m", ".v", ".compute"
@@ -246,6 +392,8 @@ class OffloadedAdam:
         # weight reads of disk bandwidth (wider Adam I/O made the whole
         # pipeline slower).
         self._io_pool: ThreadPoolExecutor | None = None  # guarded-by: _arena_lock
+        # workers and scratch of the tiled update, made at the first compute
+        self._tiles: TilePool | None = None   # guarded-by: _arena_lock
         self._closed = False     # guarded-by: _arena_lock
         # I/O volume of the most recent step
         self.last_io_bytes = 0   # guarded-by: _io_lock
@@ -309,15 +457,34 @@ class OffloadedAdam:
                     max_workers=1, thread_name_prefix="offload-optim-io")
             return self._io_pool
 
+    def _tile_pool(self) -> TilePool:
+        with self._arena_lock:
+            if self._closed:
+                raise RuntimeError("optimizer is closed")
+            if self._tiles is None:
+                # sized like the arena, by the largest subgroup: a model
+                # whose tensors are all under a tile gets one worker and
+                # scratch of that size.  Charged apart from the arena.
+                max_elems = max(s.size for s in self.subgroups.values())
+                self._tiles = TilePool(
+                    min(tile_workers(), -(-max_elems // TILE)),
+                    min(max_elems, TILE), self.tracker,
+                    f"{self.component}_tiles")
+            return self._tiles
+
     def close(self) -> None:  # thread: executor
-        """Free the staging arena's tracker charge and stop the I/O pool
-        (waiting out in-flight write-backs).  Idempotent; later streaming
-        calls raise instead of resurrecting the arena."""
+        """Free the staging arena's and the tile scratch's tracker charges
+        and stop the I/O and tile pools (waiting out in-flight
+        write-backs).  Idempotent; later streaming calls raise instead of
+        resurrecting the arena."""
         with self._arena_lock:
             self._closed = True
             pool, self._io_pool = self._io_pool, None
+            tiles, self._tiles = self._tiles, None
         if pool is not None:
             pool.shutdown(wait=True)
+        if tiles is not None:
+            tiles.close()
         with self._arena_lock:
             arena, self._arena = self._arena, None
         if arena is not None:
@@ -366,12 +533,21 @@ class OffloadedAdam:
             arena.release(buf)
             raise
 
-    def compute_subgroup(self, staged: StagedSubgroup,
-                         grad_f32: np.ndarray) -> None:  # thread: executor, optim-worker
-        """In-place :func:`adam_update` on the staged fp32 state.  Runs on
-        the optimizer thread; ``grad_f32`` is already unscaled."""
-        adam_update(staged.master, np.reshape(grad_f32, -1), staged.m,
-                    staged.v, self.step_count, self.cfg)
+    def compute_subgroup(self, staged: StagedSubgroup, grad_f32: np.ndarray,
+                         *, grad_scale: float | None = None
+                         ) -> None:  # thread: executor, optim-worker
+        """In-place :func:`adam_update` on the staged fp32 state, its tiles
+        over the optimizer's tile pool.  Runs on the optimizer thread;
+        ``grad_f32`` is multiplied by ``grad_scale`` inside the update
+        when one is given, else already unscaled."""
+        tiles = self._tile_pool()
+        n = staged.master.size
+        adam_update(staged.master, grad_f32, staged.m, staged.v,
+                    self.step_count, self.cfg, grad_scale=grad_scale,
+                    pool=tiles)
+        self.stats.bump("adam_update_elems", n)
+        if tiles.spread(n) > 1:
+            self.stats.bump("adam_parallel_elems", n)
 
     def commit_subgroup_async(self, staged: StagedSubgroup, *,
                               return_compute: bool = False

@@ -329,6 +329,10 @@ class OverlapStats:
     adam_write_seconds: float = 0.0           # guarded-by: _lock
     grad_d2h_seconds: float = 0.0             # guarded-by: _lock
     h2d_copy_seconds: float = 0.0             # guarded-by: _lock
+    adam_update_elems: int = 0    # guarded-by: _lock; entries Adam updated
+    adam_parallel_elems: int = 0  # guarded-by: _lock; of those, entries of
+    #                               subgroups whose tiles ran on more than
+    #                               one worker
     _lock: threading.Lock = field(default_factory=threading.Lock,
                                   repr=False, compare=False)
 
@@ -362,6 +366,8 @@ class OverlapStats:
             worker = {
                 "overflow_screen_seconds": self.overflow_screen_seconds,
                 "act_write_failures": self.act_write_failures,
+                "adam_update_elems": self.adam_update_elems,
+                "adam_parallel_elems": self.adam_parallel_elems,
                 **{name: getattr(self, name) for name in SPANS}}
         return {"fetch_seconds": self.fetch_seconds,
                 "h2d_gets": self.h2d_gets, "h2d_hits": self.h2d_hits,

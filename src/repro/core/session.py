@@ -1792,22 +1792,26 @@ class OffloadSession:
         with self._ostats.timed("adam_update_seconds"):
             try:
                 self.optimizer.compute_subgroup(
-                    staged, self._unit_grad(staged.key, inv_scale))
+                    staged, self._unit_grad(staged.key),
+                    grad_scale=inv_scale)
             except BaseException:
                 self.optimizer.discard_staged(staged)
                 raise
             return self.optimizer.commit_subgroup_async(staged)
 
-    def _unit_grad(self, skey: str, inv_scale: np.float32) -> np.ndarray:  # thread: executor, optim-worker
-        """Unscale one subgroup's gradient out of the flat buffer.
+    def _unit_grad(self, skey: str) -> np.ndarray:  # thread: executor, optim-worker
+        """One subgroup's still-scaled gradient: a view of the flat buffer.
 
-        Unscale with the scale the grads were produced under, not the
-        post-update one — on a growth step they differ by 2x.  The multiply
-        also copies out of the flat buffer, whose region is free for the
-        next step's write-back once the unit's readiness future resolves.
+        The update unscales it tile by tile (``grad_scale``), with the
+        scale the grads were produced under, not the post-update one — on
+        a growth step they differ by 2x.  Reading the flat buffer in place
+        is safe: the next step's gradient write-back into this region
+        gates on the unit's readiness future, which resolves only after
+        the unit's commits land, and so after its update has read the
+        region.
         """
         off, size, shape = self._flat_offsets[skey]
-        return self.flat[off:off + size].reshape(shape) * inv_scale
+        return self.flat[off:off + size].reshape(shape)
 
     # -- the pipelined Adam stage (full overlap) -----------------------------
 
@@ -1970,6 +1974,9 @@ class OffloadSession:
         for name in SPANS:
             self.metrics[name.removesuffix("_seconds") + "_s"] = (
                 o1[name] - o0[name])
+        # the update's entry counts, attributed like adam_update_s
+        for name in ("adam_update_elems", "adam_parallel_elems"):
+            self.metrics[name] = o1[name] - o0[name]
         self.metrics["overflow_screen_s"] = (
             o1["overflow_screen_seconds"] - o0["overflow_screen_seconds"])
         # activation streaming: executor stall on checkpoint saves (gating

@@ -63,11 +63,11 @@ def test_pipeline_prefetches_next_subgroup_under_compute(tmp_store_root):
             issues.append(key)          # runs FIFO on the prefetch worker
             return real_issue(key)
 
-        def compute(staged, grad):
+        def compute(staged, grad, **kw):
             # _adam_issued is optimizer-worker-thread state, read here on
             # that same thread: a deterministic probe of the window depth
             computes.append((staged.key, s._adam_issued))
-            return real_compute(staged, grad)
+            return real_compute(staged, grad, **kw)
 
         s.optimizer.issue_subgroup = issue
         s.optimizer.compute_subgroup = compute

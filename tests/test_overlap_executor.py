@@ -70,9 +70,9 @@ def test_full_overlap_runs_pipeline_legs_off_thread(tmp_store_root):
         real_commit = s.optimizer.commit_subgroup_async
         real_write = s._write_grads
 
-        def compute(staged, grad):
+        def compute(staged, grad, **kw):
             optim_threads.add(threading.current_thread().name)
-            return real_compute(staged, grad)
+            return real_compute(staged, grad, **kw)
 
         def issue(key):
             issue_threads.add(threading.current_thread().name)
@@ -196,7 +196,7 @@ def test_optimizer_worker_failure_surfaces_at_synchronize(tmp_store_root):
     b = _batches(1)[0]
     s = OffloadSession(_model(), _policy(tmp_store_root, "full"))
 
-    def failing_compute(staged, grad):
+    def failing_compute(staged, grad, **kw):
         raise IOError("injected optimizer-store failure")
 
     s.optimizer.compute_subgroup = failing_compute
@@ -215,10 +215,10 @@ def test_optimizer_worker_failure_blocks_next_step_fetch(tmp_store_root):
     real_compute = s.optimizer.compute_subgroup
     fail = {"on": True}
 
-    def flaky_compute(staged, grad):
+    def flaky_compute(staged, grad, **kw):
         if fail["on"]:
             raise IOError("injected optimizer-store failure")
-        return real_compute(staged, grad)
+        return real_compute(staged, grad, **kw)
 
     s.optimizer.compute_subgroup = flaky_compute
     s.train_step(bs[0]["tokens"], bs[0]["labels"])
@@ -240,10 +240,10 @@ def test_failed_optim_for_late_unit_never_serves_stale_weights(
     s = OffloadSession(_model(), _policy(tmp_store_root, "full"))
     real_compute = s.optimizer.compute_subgroup
 
-    def flaky_compute(staged, grad):
+    def flaky_compute(staged, grad, **kw):
         if staged.key.startswith("head/"):
             raise IOError("injected head-Adam failure")
-        return real_compute(staged, grad)
+        return real_compute(staged, grad, **kw)
 
     s.optimizer.compute_subgroup = flaky_compute
     s.train_step(b["tokens"], b["labels"])

@@ -158,9 +158,11 @@ def test_growth_step_unscales_with_pre_growth_scale(tmp_store_root):
         s.scaler.growth_interval = 1    # next good step doubles the scale
         seen = {}
         real_compute = s.optimizer.compute_subgroup
-        def recording_compute(staged, grad):
-            seen[staged.key] = np.asarray(grad, dtype=np.float32)
-            return real_compute(staged, grad)
+        def recording_compute(staged, grad, *, grad_scale):
+            # the update unscales the flat buffer's view tile by tile
+            seen[staged.key] = (np.array(grad, dtype=np.float32)
+                                * np.float32(grad_scale))
+            return real_compute(staged, grad, grad_scale=grad_scale)
         s.optimizer.compute_subgroup = recording_compute
         m = s.train_step(b["tokens"], b["labels"])
         s.synchronize()   # full overlap: Adam streams on the worker

@@ -124,3 +124,25 @@ def test_metric_reader_takes_the_window_mean(key):
     assert read(serve) is None
     # a program without the counter: the reader finds nothing, and no error
     assert read({"window_steps": [{"optim_gate_s": 9.0}]}) is None
+
+
+def test_train_step_counts_the_entries_adam_updated(tmp_store_root):
+    b = _batches(1)[0]
+    with OffloadSession(_model(), _policy(tmp_store_root, "sync")) as s:
+        m = s.train_step(b["tokens"], b["labels"])
+        total = sum(meta.size for meta in s.optimizer.subgroups.values())
+    assert m["applied"]
+    assert m["adam_update_elems"] == total
+    assert m["adam_parallel_elems"] == 0    # every tiny tensor is one tile
+
+
+def test_ns_per_elem_reader_divides_the_window_sums():
+    read = _reader("adam_update_ns_per_elem.train")
+    steps = [{"adam_update_s": 1.0, "adam_update_elems": 10 ** 8},
+             {"adam_update_s": 3.0, "adam_update_elems": 3 * 10 ** 8}]
+    assert read({"window_steps": steps}) == pytest.approx(10.0)
+    # a program without the counter, or a window that updated nothing
+    assert read({"window_steps": [{"adam_update_s": 1.0}]}) is None
+    assert read({"window_steps": [{"adam_update_s": 0.0,
+                                   "adam_update_elems": 0}]}) is None
+    assert read({"window_waves": [{"wave_s": 40.0}]}) is None

@@ -21,6 +21,7 @@ ROLES = frozenset({
     "optim-worker",    # SerialWorker "offload-optim" thread
     "optim-prefetch",  # SerialWorker "offload-optim-prefetch" thread
     "store-worker",    # store aio / direct-nvme pool threads
+    "adam-tile",       # OffloadedAdam's "offload-adam-tile" update workers
     "any",             # thread-safe: callable from every role
 })
 
